@@ -3,9 +3,9 @@
 ``SimRunner``   — advances a virtual clock with the analytical perf model
                   (frontier-scale studies; H200 constants reproduce the
                   paper's figures, v5e constants drive TPU planning).
-``JaxRunner``   — real execution of a (small) model on this host: slot-based
-                  decode cache, whole-prompt prefill scattered into the slot,
-                  batched masked decode. The paged-accounting layer in the
+``JaxRunner``   — real execution on the device: slot-based decode cache,
+                  whole-prompt prefill written into the slot, batched
+                  masked decode. The paged-accounting layer in the
                   scheduler is identical in both modes.
 """
 from __future__ import annotations
@@ -68,10 +68,15 @@ class SimRunner:
 
 
 class JaxRunner:
-    """Real execution with slot-based decode state (CPU-scale models)."""
+    """Real execution with a slot-based decode cache: each running request
+    owns one slot of ``max_len`` positions. Prefill runs the whole prompt and
+    writes its cache into the slot; decode steps every slot at once and keeps
+    the inactive ones unchanged. The cache dtype is the weights' dtype unless
+    ``ctx.kv_cache_dtype`` says otherwise; on a mesh the cache is laid out by
+    ``decode_state_shardings``."""
 
     def __init__(self, cfg: ModelConfig, params, ctx, max_slots: int,
-                 max_len: int, cache_dtype=None):
+                 max_len: int):
         import jax
         import jax.numpy as jnp
         from repro.models import transformer as T
@@ -79,86 +84,89 @@ class JaxRunner:
         self.max_slots, self.max_len = max_slots, max_len
         self._jnp = jnp
         self._T = T
-        dt = cache_dtype or jnp.float32
-        self.state = T.init_decode_state(cfg, ctx, max_slots, max_len, dt)
+        dt = params["embed"].dtype
+        self._slot_axes = T.decode_slot_axes(cfg)
+        state_sh = None if ctx.mesh is None \
+            else T.decode_state_shardings(cfg, ctx)
+        self._pin = (lambda st: st) if state_sh is None else (
+            lambda st: jax.lax.with_sharding_constraint(st, state_sh))
+        self.state = jax.jit(
+            lambda: T.init_decode_state(cfg, ctx, max_slots, max_len, dt),
+            out_shardings=state_sh)()
         self._free_slots = list(range(max_slots))[::-1]
         self._slot_of: Dict[int, int] = {}
         self._prefill_fn = jax.jit(
             lambda p, tok: T.prefill(p, tok, cfg, ctx, max_len=max_len,
                                      cache_dtype=dt))
-        self._decode_fn = jax.jit(
-            lambda p, st, tok, active: self._masked_decode(p, st, tok, active))
+        # the state is donated: each step updates the cache in place
+        self._insert_fn = jax.jit(self._insert, donate_argnums=(0,))
+        self._decode_fn = jax.jit(self._masked_decode, donate_argnums=(1,))
+
+    def _insert(self, state, fresh, slot):
+        import jax
+        return self._pin(jax.tree_util.tree_map(
+            lambda dst, src, ax: jax.lax.dynamic_update_slice_in_dim(
+                dst, src.astype(dst.dtype), slot, ax),
+            state, fresh, self._slot_axes))
 
     def _masked_decode(self, params, state, tokens, active):
+        import jax
         logits, new_state = self._T.decode_step(params, state, tokens,
                                                 self.cfg, self.ctx)
-        # keep inactive slots untouched
-        merged = self._tree_select(new_state, state, active)
-        return logits, merged
 
-    def _bmask(self, active, arr):
-        jnp = self._jnp
-        # the slot axis is the unique axis whose size == max_slots (engine
-        # tests must pick max_slots distinct from structural dims)
-        matches = [ax for ax in range(arr.ndim)
-                   if arr.shape[ax] == self.max_slots]
-        if not matches:
-            return jnp.ones((), bool)
-        assert len(matches) == 1, \
-            f"ambiguous slot axis for shape {arr.shape}; pick another max_slots"
-        shape = [1] * arr.ndim
-        shape[matches[0]] = self.max_slots
-        return active.reshape(shape)
-
-    def _tree_select(self, new, old, active):
-        import jax
-        return jax.tree_util.tree_map(
-            lambda n, o: self._jnp.where(self._bmask(active, n), n, o)
-            if n.ndim else n, new, old)
+        def keep_inactive(new, old, ax):
+            shape = [1] * new.ndim
+            shape[ax] = self.max_slots
+            return self._jnp.where(active.reshape(shape), new, old)
+        merged = jax.tree_util.tree_map(keep_inactive, new_state, state,
+                                        self._slot_axes)
+        return logits[:, 0], self._pin(merged)
 
     # ------------------------------------------------------------------ api
+    def prefill_slot(self, slot: int, tokens: List[int]):
+        """Run a whole prompt and write its cache into ``slot``. Returns the
+        logits (V,) at the prompt's last position."""
+        last, fresh = self._prefill_fn(
+            self.params, self._jnp.asarray([tokens], self._jnp.int32))
+        self.state = self._insert_fn(self.state, fresh, slot)
+        return last[0]
+
+    def decode_slots(self, tokens: np.ndarray, active: np.ndarray):
+        """One decode step over every slot: ``tokens`` (max_slots,) are the
+        inputs, ``active`` (max_slots,) marks the slots to advance. Returns
+        logits (max_slots, V)."""
+        jnp = self._jnp
+        # jnp.array copies: jnp.asarray may alias host memory that the
+        # caller reuses while the step is still in flight
+        logits, self.state = self._decode_fn(
+            self.params, self.state, jnp.array(tokens[:, None], jnp.int32),
+            jnp.array(active))
+        return logits
+
     def prefill(self, req: Request, chunk: int) -> int:
         """Whole-prompt prefill into the request's slot; returns first token."""
-        import jax
-        jnp = self._jnp
+        toks = req.prompt + req.output[:req.resume_extra]
+        # a slot holds max_len positions, and an out-of-range cache write
+        # would be dropped silently on the device
+        if req.isl + req.max_new_tokens - 1 > self.max_len:
+            raise ValueError(
+                f"request {req.rid}: {req.isl} prompt + {req.max_new_tokens} "
+                f"new tokens exceed the {self.max_len}-position slot")
         if req.rid not in self._slot_of:
             self._slot_of[req.rid] = self._free_slots.pop()
-        slot = self._slot_of[req.rid]
-        toks = req.prompt + req.output[:req.resume_extra]
-        tokens = jnp.asarray([toks], jnp.int32)
-        last, fresh = self._prefill_fn(self.params, tokens)
-        self.state = self._scatter_slot(self.state, fresh, slot)
-        return int(jnp.argmax(last[0]))
-
-    def _scatter_slot(self, state, fresh, slot):
-        import jax
-
-        def put(dst, src):
-            if dst.ndim == 0:
-                return dst
-            for ax in range(dst.ndim):
-                if dst.shape[ax] == self.max_slots and src.shape[ax] == 1:
-                    idx = [slice(None)] * dst.ndim
-                    idx[ax] = slice(slot, slot + 1)
-                    if ax + 1 < dst.ndim and dst.shape[ax + 1] != src.shape[ax + 1]:
-                        # seq axis shorter in fresh state: write the prefix
-                        idx[ax + 1] = slice(0, src.shape[ax + 1])
-                    return dst.at[tuple(idx)].set(src)
-            return dst
-        return jax.tree_util.tree_map(put, state, fresh)
+        logits = self.prefill_slot(self._slot_of[req.rid], toks)
+        return int(self._jnp.argmax(logits))
 
     def decode(self, reqs: List[Request]) -> List[int]:
-        jnp = self._jnp
         slots = [self._slot_of[r.rid] for r in reqs]
-        tokens = np.zeros((self.max_slots, 1), np.int32)
+        tokens = np.zeros((self.max_slots,), np.int32)
         active = np.zeros((self.max_slots,), bool)
         for r, s in zip(reqs, slots):
-            last = r.output[-1] if r.output else (r.prompt[-1] if r.prompt else 0)
-            tokens[s, 0] = last
+            tokens[s] = r.output[-1] if r.output else (
+                r.prompt[-1] if r.prompt else 0)
             active[s] = True
-        logits, self.state = self._decode_fn(
-            self.params, self.state, jnp.asarray(tokens), jnp.asarray(active))
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        logits = self.decode_slots(tokens, active)
+        nxt = np.asarray(self._jnp.argmax(logits, axis=-1))
         return [int(nxt[s]) for s in slots]
 
     def release(self, req: Request):

@@ -4,10 +4,11 @@ Grid: (batch, q_heads, num_q_blocks, num_kv_blocks) — the kv-block axis is the
 innermost (sequential) dimension; online-softmax stats (m, l) and the output
 accumulator live in VMEM scratch and persist across kv-block steps.
 
-BlockSpec tiling (MXU-aligned 128x128 defaults):
-  q   (1, block_q, 1, D)   revisited for every kv block
-  k/v (1, block_k, 1, D)   kv head = q_head // group
-  out (1, block_q, 1, D)   written once, on the last kv block
+Head-major layout, so the last two dims of every block are (rows, D) and
+satisfy the TPU tiling rule for any head_dim (D is the full last dim):
+  q   (1, 1, block_q, D)   revisited for every kv block
+  k/v (1, 1, block_k, D)   kv head = q_head // group
+  out (1, 1, block_q, D)   written once, on the last kv block
 
 Causal + sliding-window masking is applied inside the kernel from the global
 block offsets; kv blocks strictly above the diagonal (or outside the window)
@@ -55,9 +56,9 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0].astype(jnp.float32) * scale
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(q.astype(k.dtype), k,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -72,24 +73,24 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
             valid = jnp.logical_and(valid, k_pos > q_pos - window)
         s = jnp.where(valid, s, NEG_INF)
 
+        # stats are (block_q, 1) columns: TPU vectors are 2-D
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # explicit re-mask: fully-masked rows would otherwise get exp(0)=1
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(p.astype(v.dtype), v,
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finish():
         l = l_ref[...]
-        out = jnp.where(l[:, None] > 0,
-                        acc_ref[...] / jnp.maximum(l[:, None], 1e-30), 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        out = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -99,10 +100,10 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
 def flash_attention_kernel(q, k, v, lens, *, causal=True, window=0,
                            scale=None, block_q=128, block_k=128,
                            interpret=False):
-    """q (B,Sq,H,D); k,v (B,Skv,KV,D); lens (B,) int32 valid kv length.
-    Returns (B,Sq,H,D). H % KV == 0 (GQA via kv-head revisiting)."""
-    B, Sq, H, D = q.shape
-    Skv, KV = k.shape[1], k.shape[2]
+    """q (B,H,Sq,D); k,v (B,KV,Skv,D); lens (B,) int32 valid kv length.
+    Returns (B,H,Sq,D). H % KV == 0 (GQA via kv-head revisiting)."""
+    B, H, Sq, D = q.shape
+    KV, Skv = k.shape[1], k.shape[2]
     g = H // KV
     scale = scale if scale is not None else D ** -0.5
     block_q = min(block_q, Sq)
@@ -121,21 +122,21 @@ def flash_attention_kernel(q, k, v, lens, *, causal=True, window=0,
             grid=grid,
             in_specs=[
                 # index maps receive the scalar-prefetch ref as a trailing arg
-                pl.BlockSpec((1, block_q, 1, D),
-                             lambda b, h, iq, ik, lens: (b, iq, h, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, iq, ik, lens: (b, ik, h // g, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, iq, ik, lens: (b, ik, h // g, 0)),
+                pl.BlockSpec((1, 1, block_q, D),
+                             lambda b, h, iq, ik, lens: (b, h, iq, 0)),
+                pl.BlockSpec((1, 1, block_k, D),
+                             lambda b, h, iq, ik, lens: (b, h // g, ik, 0)),
+                pl.BlockSpec((1, 1, block_k, D),
+                             lambda b, h, iq, ik, lens: (b, h // g, ik, 0)),
             ],
-            out_specs=pl.BlockSpec((1, block_q, 1, D),
-                                   lambda b, h, iq, ik, lens: (b, iq, h, 0)),
+            out_specs=pl.BlockSpec((1, 1, block_q, D),
+                                   lambda b, h, iq, ik, lens: (b, h, iq, 0)),
             scratch_shapes=[
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         interpret=interpret,
     )(lens.astype(jnp.int32), q, k, v)

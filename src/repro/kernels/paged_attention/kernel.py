@@ -9,9 +9,11 @@ block table).
 Grid: (batch, kv_heads, num_pages) — pages innermost/sequential; the q-group
 accumulator (g, D) and stats live in VMEM scratch across page steps.
 
-  q        (B, KV, G, D)    revisited per page
-  k/v page (1, page, 1, D)  page id = block_table[b, j]
-  out      (B, KV, G, D)    written on the last page
+The pool is head-major (KV, P, page, D), so the last two dims of every block
+are (rows, D) and satisfy the TPU tiling rule for any head_dim:
+  q        (1, 1, G, D)     revisited per page
+  k/v page (1, 1, page, D)  page id = block_table[b, j]
+  out      (1, 1, G, D)     written on the last page
 
 Pages past ceil(len/page) are skipped with pl.when (DMA still issued for the
 block — acceptable at page granularity; a fully dynamic grid would need
@@ -46,9 +48,9 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j < n_used)
     def _step():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale     # (G, D)
-        k = k_ref[0, :, 0, :]                                 # (page, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0].astype(jnp.float32) * scale           # (G, D)
+        k = k_ref[0, 0]                                       # (page, D)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(q.astype(k.dtype), k,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G,page)
@@ -56,12 +58,13 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.int32, s.shape, 1)
         valid = pos < seq_len
         s = jnp.where(valid, s, NEG_INF)
+        # stats are (G, 1) columns: TPU vectors are 2-D
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -69,19 +72,18 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == npg - 1)
     def _finish():
         l = l_ref[...]
-        out = jnp.where(l[:, None] > 0,
-                        acc_ref[...] / jnp.maximum(l[:, None], 1e-30), 0.0)
-        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
+        out = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "interpret"))
 def paged_attention_kernel(q, k_pages, v_pages, block_tables, lens, *,
                            scale=None, interpret=False):
-    """q (B,KV,G,D); k/v_pages (P, page, KV, D); block_tables (B, max_blocks)
+    """q (B,KV,G,D); k/v_pages (KV, P, page, D); block_tables (B, max_blocks)
     int32 page ids; lens (B,) index of the newest token. Returns (B,KV,G,D)."""
     B, KV, G, D = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     max_blocks = block_tables.shape[1]
     scale = scale if scale is not None else D ** -0.5
     grid = (B, KV, max_blocks)
@@ -95,18 +97,18 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lens, *,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D),
                              lambda b, h, j, tables, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, page, 1, D),
+                pl.BlockSpec((1, 1, page, D),
                              lambda b, h, j, tables, lens:
-                             (tables[b, j], 0, h, 0)),
-                pl.BlockSpec((1, page, 1, D),
+                             (h, tables[b, j], 0, 0)),
+                pl.BlockSpec((1, 1, page, D),
                              lambda b, h, j, tables, lens:
-                             (tables[b, j], 0, h, 0)),
+                             (h, tables[b, j], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, D),
                                    lambda b, h, j, tables, lens: (b, h, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
                 pltpu.VMEM((G, D), jnp.float32),
             ],
         ),

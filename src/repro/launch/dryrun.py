@@ -192,6 +192,7 @@ def main():
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]
 
+    failed = []
     for arch, shape in todo:
         for mp in meshes:
             name = f"{arch}__{shape}__{'multi' if mp else 'single'}__{args.tag}"
@@ -202,7 +203,8 @@ def main():
             print(f"[dryrun] {name} ...", flush=True)
             try:
                 res = lower_cell(arch, shape, mp, opts)
-            except Exception as e:  # record failures for triage
+            except Exception as e:  # record the failure, run the other cells
+                failed.append(name)
                 res = {"arch": arch, "shape": shape,
                        "mesh": "multi" if mp else "single",
                        "error": f"{type(e).__name__}: {e}",
@@ -216,6 +218,8 @@ def main():
                 f"frac={res['roofline']['roofline_fraction']:.3f} "
                 f"compile={res['compile_s']}s")
             print(f"[done] {name}: {status}", flush=True)
+    if failed:
+        raise SystemExit(f"{len(failed)} cell(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,8 +1,13 @@
 """Serving launcher.
 
-Real mode (CPU-runnable, reduced config):
+Real mode, float32 at reduced widths (runs on the CPU):
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --smoke \
         --requests 8
+
+Real mode, bfloat16 at published widths (needs an accelerator that holds the
+weights):
+    PYTHONPATH=src python -m repro.launch.serve --arch h2o-danube-3-4b \
+        --requests 8 --max-num-seqs 8
 
 Simulated fleet mode (paper-scale characterization):
     PYTHONPATH=src python -m repro.launch.serve --arch llama3-405b --sim \
@@ -22,6 +27,8 @@ from repro.core.router import DPRouter, RouterConfig
 from repro.core.runner import JaxRunner, SimRunner
 from repro.data.reasoning import REASONING, sample
 
+PAGE = 16      # KV page size (tokens) of the real-mode engine
+
 
 def build_sim_fleet(cfg, args):
     hw = {"h200": pm.H200, "v5e": pm.V5E}[args.hw]
@@ -35,6 +42,28 @@ def build_sim_fleet(cfg, args):
     replicas = [InferenceEngine(cfg, ecfg, SimRunner(cfg, plan, hw))
                 for _ in range(args.dp)]
     return DPRouter(replicas, RouterConfig(policy=args.router))
+
+
+def build_real_engine(cfg, *, dtype, max_slots: int, max_len: int,
+                      seed: int = 0, ctx=None,
+                      admission_mode: str = "kv_aware") -> InferenceEngine:
+    """The real serving path: random weights drawn from ``seed`` in
+    ``dtype``, a ``JaxRunner`` with ``max_slots`` cache slots of ``max_len``
+    positions, and an engine whose page pool and concurrency cap hold
+    exactly those slots. ``ctx`` (default: one device) places the weights
+    and the cache."""
+    import jax
+    from repro.models import transformer as T
+    from repro.parallel.sharding import single_device_ctx
+    ctx = ctx or single_device_ctx()
+    params = T.init_params(cfg, jax.random.PRNGKey(seed), ctx, mode="serve",
+                           dtype=dtype)
+    runner = JaxRunner(cfg, params, ctx, max_slots=max_slots, max_len=max_len)
+    ecfg = EngineConfig(n_pages=max_slots * max_len // PAGE,
+                        max_num_seqs=max_slots,
+                        max_num_batched_tokens=max_len, chunk_size=max_len,
+                        page_size=PAGE, admission_mode=admission_mode)
+    return InferenceEngine(cfg, ecfg, runner, virtual_clock=False)
 
 
 def main():
@@ -78,24 +107,20 @@ def main():
         return
 
     # real execution
-    import jax
     import jax.numpy as jnp
-    from repro.models import transformer as T
-    from repro.parallel.sharding import single_device_ctx
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    ctx = single_device_ctx()
-    params = T.init_params(cfg, jax.random.PRNGKey(args.seed), ctx,
-                           mode="serve", dtype=jnp.float32)
-    max_len = 192
-    runner = JaxRunner(cfg, params, ctx, max_slots=8, max_len=max_len)
-    ecfg = EngineConfig(n_pages=8 * max_len // 16, max_num_seqs=8,
-                        max_num_batched_tokens=1024, chunk_size=max_len,
-                        admission_mode=args.admission)
-    eng = InferenceEngine(cfg, ecfg, runner, virtual_clock=False)
     rng = np.random.default_rng(args.seed)
-    for _ in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab, size=int(rng.integers(4, 24)))
-        eng.submit(prompt.tolist(), int(rng.integers(8, 32)))
+    reqs = [(rng.integers(0, cfg.vocab, size=int(rng.integers(4, 24))).tolist(),
+             int(rng.integers(8, 32))) for _ in range(args.requests)]
+    # slots sized to the workload: the longest request, rounded to a page
+    longest = max(len(p) + n for p, n in reqs)
+    eng = build_real_engine(
+        cfg, dtype=jnp.float32 if args.smoke else jnp.bfloat16,
+        max_slots=min(args.max_num_seqs, args.requests),
+        max_len=-(-longest // PAGE) * PAGE, seed=args.seed,
+        admission_mode=args.admission)
+    for prompt, n_new in reqs:
+        eng.submit(prompt, n_new)
     m = eng.run()
     s = m.summary()
     print(json.dumps({k: v for k, v in s.items() if not isinstance(v, dict)},

@@ -11,7 +11,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding
 
 from repro.configs.base import ModelConfig
 from repro.configs.registry import ShapeSpec
@@ -97,48 +97,5 @@ def input_specs(cfg: ModelConfig, shape: ShapeSpec, ctx: ParallelContext,
         "batch": {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32)},
         "shardings": {"tokens": tok_sh},
         "state": state,
-        "state_shardings": state_shardings(cfg, ctx),
+        "state_shardings": T.decode_state_shardings(cfg, ctx),
     }
-
-
-def state_pspecs(cfg: ModelConfig, ctx: ParallelContext):
-    """PartitionSpec tree matching init_decode_state's structure."""
-    sp: Dict[str, Any] = {"lens": ctx.spec("cache_batch")}
-    kv_sp = ctx.spec("layers", "cache_batch", "cache_seq", "cache_kv", None)
-    mla_sp = ctx.spec("layers", "cache_batch", "cache_seq", None)
-    if cfg.family in ("dense", "vlm", "audio", "moe"):
-        caches = {}
-        n_dense = cfg.moe.first_dense_layers if (cfg.moe and cfg.moe.n_experts) \
-            else cfg.n_layers
-        n_moe = cfg.n_layers - n_dense if (cfg.moe and cfg.moe.n_experts) else 0
-        for name, n in (("dense_stack", n_dense), ("moe_stack", n_moe)):
-            if n == 0:
-                continue
-            if cfg.attention == "mla":
-                caches[name] = {"ckv": mla_sp, "kpe": mla_sp}
-            else:
-                caches[name] = {"k": kv_sp, "v": kv_sp}
-        sp["caches"] = caches
-    elif cfg.family == "hybrid":
-        sp["caches"] = {"shared_attn": {"k": kv_sp, "v": kv_sp}}
-        h_sp = ctx.spec("layers", "cache_batch", "ssm_heads", None, None)
-        cs_x = ctx.spec("layers", "cache_batch", None, "ssm_inner")
-        cs_bc = ctx.spec("layers", "cache_batch", None, None)
-        sp["mamba"] = (h_sp, (cs_x, cs_bc, cs_bc))
-    elif cfg.family == "ssm":
-        two = ctx.spec("layers", "layers")
-        def m(*rest):
-            return ctx.spec("layers", "layers", "cache_batch", *rest)
-        sp["mlstm"] = (m(None, None, None), m(None, None), m(None),
-                       m(None, None))
-        def s(*rest):
-            return ctx.spec("layers", "cache_batch", *rest)
-        sp["slstm"] = (s(None), s(None), s(None), s(None))
-    return sp
-
-
-def state_shardings(cfg: ModelConfig, ctx: ParallelContext):
-    sp = state_pspecs(cfg, ctx)
-    return jax.tree_util.tree_map(
-        lambda p: NamedSharding(ctx.mesh, p), sp,
-        is_leaf=lambda x: isinstance(x, P))

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import ParallelContext, shard_map
+from repro.parallel.sharding import ParallelContext
 
 
 def router_probs(x, w_router):
@@ -176,12 +176,12 @@ def moe_ffn(x, params, cfg, ctx: ParallelContext, *, token_axes) -> jax.Array:
         sh = params.get("ws_gate", jnp.zeros((), x.dtype))
         su_ = params.get("ws_up", jnp.zeros((), x.dtype))
         sd_ = params.get("ws_down", jnp.zeros((), x.dtype))
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P((*(ctx.batch_axes), maxis)), P(None, None),
                       P(maxis, faxis, None), P(maxis, faxis, None),
                       P(maxis, None, faxis), *shared_specs),
-            out_specs=tok_spec, check=False,
+            out_specs=tok_spec, check_vma=False,
         )(xt, params["router"], params["we_gate"], params["we_up"],
           params["we_down"], sh, su_, sd_)
         return out.reshape(shape)
@@ -250,10 +250,10 @@ def moe_ffn(x, params, cfg, ctx: ParallelContext, *, token_axes) -> jax.Array:
     sh = params.get("ws_gate", jnp.zeros((), x.dtype))
     su_ = params.get("ws_up", jnp.zeros((), x.dtype))
     sd_ = params.get("ws_down", jnp.zeros((), x.dtype))
-    out = shard_map(
+    out = jax.shard_map(
         body_rep, mesh=mesh,
         in_specs=(tok_spec, P(None, None), *wspecs, *shared_specs),
-        out_specs=tok_spec, check=False,
+        out_specs=tok_spec, check_vma=False,
     )(xt, params["router"], params["we_gate"], params["we_up"],
       params["we_down"], sh, su_, sd_)
     return out.reshape(shape)

@@ -268,9 +268,12 @@ def build_param_specs(cfg: ModelConfig, ctx: ParallelContext, mode: str = "train
 
 def init_params(cfg: ModelConfig, key, ctx: ParallelContext, mode: str = "train",
                 dtype=jnp.float32):
+    """Random weights from ``key``. One jitted program draws every leaf
+    straight into ``dtype`` (no eager float32 copy of a whole stack) and, on
+    a mesh, onto its ``param_shardings`` layout; the values do not depend on
+    the layout (threefry is partitionable)."""
     specs = build_param_specs(cfg, ctx, mode)
     leaves, treedef = jax.tree_util.tree_flatten(specs, is_leaf=_is_spec)
-    keys = jax.random.split(key, len(leaves))
 
     def make(spec: ParamSpec, k):
         if spec.init == "zeros":
@@ -279,9 +282,14 @@ def init_params(cfg: ModelConfig, key, ctx: ParallelContext, mode: str = "train"
             return jnp.ones(spec.shape, dtype)
         return dense_init(k, spec.shape, max(spec.fan_in, 1), dtype)
 
-    vals = [make(s, k) for s, k in zip(leaves, keys)]
-    params = jax.tree_util.tree_unflatten(treedef, vals)
-    return _postprocess_init(params, cfg, ctx, mode)
+    def build(key):
+        keys = jax.random.split(key, len(leaves))
+        vals = [make(s, k) for s, k in zip(leaves, keys)]
+        params = jax.tree_util.tree_unflatten(treedef, vals)
+        return _postprocess_init(params, cfg, ctx, mode)
+
+    out = param_shardings(cfg, ctx, mode) if ctx.mesh is not None else None
+    return jax.jit(build, out_shardings=out)(key)
 
 
 def _postprocess_init(params, cfg, ctx, mode):
@@ -599,20 +607,69 @@ def loss_fn(params, batch, cfg: ModelConfig, ctx: ParallelContext):
 
 
 # --------------------------------------------------------------- serve paths
+def _cache_stacks(cfg: ModelConfig):
+    """(name, layers) of each attention stack that keeps a decode cache."""
+    if cfg.moe and cfg.moe.n_experts:
+        nd = cfg.moe.first_dense_layers
+        stacks = (("dense_stack", nd), ("moe_stack", cfg.n_layers - nd))
+    else:
+        stacks = (("dense_stack", cfg.n_layers),)
+    return [(name, n) for name, n in stacks if n]
+
+
+def _is_axes(x) -> bool:
+    return isinstance(x, tuple) and all(a is None or isinstance(a, str)
+                                        for a in x)
+
+
+def decode_state_axes(cfg: ModelConfig):
+    """Logical axes of every leaf of ``init_decode_state``'s tree, in the
+    same structure. "cache_batch" is each leaf's slot axis."""
+    axes: Dict[str, Any] = {"lens": ("cache_batch",)}
+    kv = ("layers", "cache_batch", "cache_seq", "cache_kv", None)
+    if cfg.family in ("dense", "vlm", "audio", "moe"):
+        lat = ("layers", "cache_batch", "cache_seq", None)
+        leaf = {"ckv": lat, "kpe": lat} if cfg.attention == "mla" \
+            else {"k": kv, "v": kv}
+        axes["caches"] = {name: leaf for name, _ in _cache_stacks(cfg)}
+    elif cfg.family == "hybrid":
+        axes["caches"] = {"shared_attn": {"k": kv, "v": kv}}
+        bc = ("layers", "cache_batch", None, None)
+        axes["mamba"] = (("layers", "cache_batch", "ssm_heads", None, None),
+                         (("layers", "cache_batch", None, "ssm_inner"), bc, bc))
+    elif cfg.family == "ssm":
+        def m(*rest):
+            return ("layers", "layers", "cache_batch", *rest)
+        axes["mlstm"] = (m(None, None, None), m(None, None), m(None),
+                         m(None, None))
+        s = ("layers", "cache_batch", None)
+        axes["slstm"] = (s, s, s, s)
+    return axes
+
+
+def decode_slot_axes(cfg: ModelConfig):
+    """The slot (batch) axis of every decode-state leaf, as an int tree."""
+    return jax.tree_util.tree_map(lambda a: a.index("cache_batch"),
+                                  decode_state_axes(cfg), is_leaf=_is_axes)
+
+
+def decode_state_shardings(cfg: ModelConfig, ctx: ParallelContext):
+    return jax.tree_util.tree_map(
+        lambda a: NamedSharding(ctx.mesh, ctx.spec(*a)),
+        decode_state_axes(cfg), is_leaf=_is_axes)
+
+
 def init_decode_state(cfg: ModelConfig, ctx: ParallelContext, batch: int,
                       max_len: int, dtype=jnp.bfloat16):
-    """Allocate the decode cache pytree (dense ring-buffer layout)."""
+    """Allocate the decode cache pytree (dense ring-buffer layout); its
+    leaves' logical axes are ``decode_state_axes``."""
     hd = cfg.resolved_head_dim
     hp, kvp = heads_layout(cfg, ctx, "serve")
     state: Dict[str, Any] = {"lens": jnp.zeros((batch,), jnp.int32)}
     cdt = ctx.kv_cache_dtype or dtype
     if cfg.family in ("dense", "vlm", "audio", "moe"):
-        n_dense = cfg.moe.first_dense_layers if (cfg.moe and cfg.moe.n_experts) else cfg.n_layers
-        n_moe = cfg.n_layers - n_dense if (cfg.moe and cfg.moe.n_experts) else 0
         caches = {}
-        for name, n in (("dense_stack", n_dense), ("moe_stack", n_moe)):
-            if n == 0:
-                continue
+        for name, n in _cache_stacks(cfg):
             if cfg.attention == "mla":
                 ml = cfg.mla
                 caches[name] = {

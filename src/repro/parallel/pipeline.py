@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map
-
 
 def pipeline_forward(stage_fn: Callable, params_stacked, x, *, mesh: Mesh,
                      n_micro: int, stage_axis: str = "stage"):
@@ -76,8 +74,8 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x, *, mesh: Mesh,
         return outs.reshape(B, *outs.shape[2:])
 
     in_specs = (P(stage_axis), P())
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check=False)(params_stacked, x)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(params_stacked, x)
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:

@@ -24,18 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """jax.shard_map across jax versions: top-level `jax.shard_map(check_vma=)`
-    (>= 0.6) vs `jax.experimental.shard_map.shard_map(check_rep=)`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,8 +135,17 @@ def single_device_ctx() -> ParallelContext:
     return ParallelContext(mesh=None)
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices=None) -> Mesh:
+    """A mesh whose axes are all Auto: the model code relies on GSPMD to
+    propagate shardings, which Explicit axes (``jax.make_mesh``'s default)
+    would refuse."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_test_mesh(data: int = 1, model: int = 1) -> Mesh:
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def padded_heads(n_heads: int, n_kv: int, tp: int) -> Tuple[int, int]:

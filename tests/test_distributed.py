@@ -19,6 +19,7 @@ def _run(body: str):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.parallel.sharding import make_mesh, make_test_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
@@ -48,7 +49,7 @@ def test_moe_ep_matches_reference():
              "we_down": jax.random.normal(ks[3], (8, 48, 32)) * 0.1}
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
         ref = moe_ffn_reference(x.reshape(-1, 32), p, cfg).reshape(x.shape)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_test_mesh(2, 4)
         for mode in ("split", "replicated"):
             ctx = ParallelContext(mesh=mesh, fsdp_axis=None, moe_dispatch=mode)
             out = jax.jit(lambda x: moe_ffn(x, p, cfg, ctx, token_axes=None))(x)
@@ -65,7 +66,7 @@ def test_sharded_forward_all_families():
         from repro.models import transformer as T
         from repro.parallel.sharding import ParallelContext
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_test_mesh(4, 2)
         for arch in ["qwen3-14b", "phi3.5-moe-42b-a6.6b", "zamba2-2.7b",
                      "xlstm-350m", "musicgen-medium", "kimi-k2-1t-a32b"]:
             cfg = get_smoke_config(arch)
@@ -87,7 +88,7 @@ def test_sharded_forward_all_families():
 def test_pipeline_equivalence():
     _run("""
         from repro.parallel.pipeline import pipeline_forward
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         W = jax.random.normal(jax.random.PRNGKey(0), (4, 32, 32)) * 0.3
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 32))
         stage = lambda w, xm: jnp.tanh(xm @ w)
@@ -116,7 +117,7 @@ def test_train_step_sharded_with_zero_sharded_optimizer():
             opt_state_shardings
         from repro.train.train_step import make_train_step
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_test_mesh(4, 2)
         cfg = get_smoke_config("llama3.2-3b")
         ctx = ParallelContext(mesh=mesh, remat="full")
         ocfg = AdamWConfig(lr=1e-3, warmup_steps=1)
@@ -152,14 +153,14 @@ def test_elastic_checkpoint_restore_across_meshes():
         cfg = get_smoke_config("llama3.2-3b")
         # writer: single device, tp=1 layout is the (4,2)-mesh layout too —
         # use the SAME ctx family (padded for tp=2) so structures match
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_test_mesh(4, 2)
         ctx = ParallelContext(mesh=mesh)
         p = T.init_params(cfg, jax.random.PRNGKey(0), ctx, mode="train",
                           dtype=jnp.float32)
         d = tempfile.mkdtemp()
         ckpt.save(p, d, step=3)
         # reader: different mesh shape (2, 4) — elastic re-shard on restore
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = make_test_mesh(2, 4)
         ctx2 = ParallelContext(mesh=mesh2)
         # same padded head count needed for identical param STRUCTURE:
         # tp=2 vs tp=4 both pad 24->24? llama3.2 smoke heads=4, kv=2:

@@ -37,9 +37,9 @@ def small_model():
     return cfg, params
 
 
-def _run_engine(cfg, params, prompts, n_new, n_pages):
-    runner = JaxRunner(cfg, params, CTX, max_slots=4, max_len=192)
-    ecfg = EngineConfig(n_pages=n_pages, max_num_seqs=4,
+def _run_engine(cfg, params, prompts, n_new, n_pages, max_slots=4):
+    runner = JaxRunner(cfg, params, CTX, max_slots=max_slots, max_len=192)
+    ecfg = EngineConfig(n_pages=n_pages, max_num_seqs=max_slots,
                         max_num_batched_tokens=512, chunk_size=192,
                         admission_mode="naive")
     eng = InferenceEngine(cfg, ecfg, runner, virtual_clock=False)
@@ -56,6 +56,56 @@ def test_engine_matches_greedy(small_model):
     n_new = [6, 4, 8]
     reqs = _run_engine(cfg, params, prompts, n_new, n_pages=64)
     for p, n, r in zip(prompts, n_new, reqs):
+        assert r.output == _greedy_reference(cfg, params, p, n)
+
+
+def test_engine_slots_equal_to_kv_heads(small_model):
+    """max_slots equal to the KV-head count (and the layer count): each
+    cache leaf's slot axis is declared by the model, not found by size."""
+    cfg, params = small_model
+    assert cfg.n_kv_heads == cfg.n_layers == 2
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, cfg.vocab, size=n).tolist() for n in (9, 4, 6)]
+    n_new = [5, 7, 3]
+    reqs = _run_engine(cfg, params, prompts, n_new, n_pages=64,
+                       max_slots=cfg.n_kv_heads)
+    for p, n, r in zip(prompts, n_new, reqs):
+        assert r.output == _greedy_reference(cfg, params, p, n)
+
+
+def test_runner_refuses_request_longer_than_slot(small_model):
+    """A request whose prompt and output overrun its slot is refused: the
+    device would drop the out-of-range cache writes without an error."""
+    cfg, params = small_model
+    runner = JaxRunner(cfg, params, CTX, max_slots=2, max_len=16)
+    eng = InferenceEngine(cfg, EngineConfig(n_pages=8, max_num_seqs=2,
+                                            max_num_batched_tokens=64,
+                                            chunk_size=16),
+                          runner, virtual_clock=False)
+    eng.submit(list(range(10)), 8)
+    with pytest.raises(ValueError, match="16-position slot"):
+        eng.run(max_steps=10)
+
+
+def test_build_real_engine_serves_smoke_config():
+    """The real-mode builder shared by the serve launcher and the chip
+    smoke check serves every request to its requested length, and its
+    outputs are the greedy continuation of its own weights."""
+    from repro.launch.serve import build_real_engine
+    cfg = get_smoke_config("h2o-danube-3-4b")
+    eng = build_real_engine(cfg, dtype=jnp.float32, max_slots=3, max_len=64,
+                            seed=5)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab, size=n).tolist()
+               for n in (12, 30, 7, 20)]
+    n_new = [10, 6, 12, 9]
+    reqs = [eng.submit(p, n) for p, n in zip(prompts, n_new)]
+    eng.run(max_steps=2000)
+    assert eng.metrics.summary()["n_finished"] == len(reqs)
+    params = eng.runner.params
+    assert params["embed"].dtype == jnp.float32
+    for p, n, r in zip(prompts, n_new, reqs):
+        assert len(r.output) == n
         assert r.output == _greedy_reference(cfg, params, p, n)
 
 

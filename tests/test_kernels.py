@@ -40,7 +40,7 @@ def test_flash_vs_ref(case, dtype):
                    D, dtype)
     lens = jnp.asarray([Skv] + [max(Skv // 2, 1)] * (B - 1), jnp.int32)
     out = flash_attention(q, k, v, lens, causal=True, window=window,
-                          block_q=bq, block_k=bk)
+                          block_q=bq, block_k=bk, interpret=True)
     ref = flash_attention_ref(q, k, v, lens, causal=True, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -55,7 +55,8 @@ def test_flash_matches_model_flash_jnp():
     q, k, v = _qkv(jax.random.PRNGKey(7), B, S, S, H, KV, D, jnp.float32)
     pos = jnp.arange(S, dtype=jnp.int32)[None, :]
     out_jnp = flash_prefill(q, k, v, q_positions=pos, block_k=64)
-    out_kernel = flash_attention(q, k, v, block_q=64, block_k=64)
+    out_kernel = flash_attention(q, k, v, block_q=64, block_k=64,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(out_jnp), np.asarray(out_kernel),
                                rtol=2e-3, atol=2e-3)
 
@@ -81,7 +82,7 @@ def test_paged_vs_ref(case, dtype):
     vp = jax.random.normal(ks[2], (P, page, KV, D), jnp.float32).astype(dtype)
     tables = jax.random.randint(ks[3], (B, nblk), 0, P)
     lens = jnp.asarray([(nblk * page) - 1] + [page // 2] * (B - 1), jnp.int32)
-    out = paged_attention(q, kp, vp, tables, lens)
+    out = paged_attention(q, kp, vp, tables, lens, interpret=True)
     ref = paged_attention_ref(q.reshape(B, KV, G, D), kp, vp, tables,
                               lens).reshape(B, H, D)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
@@ -106,6 +107,7 @@ def test_paged_matches_dense_decode():
     kp = kc.reshape(B * nblk, page, KV, D)
     vp = vc.reshape(B * nblk, page, KV, D)
     tables = jnp.arange(B * nblk, dtype=jnp.int32).reshape(B, nblk)
-    paged = paged_attention(q[:, 0], kp, vp, tables, lens).reshape(B, 1, H, D)
+    paged = paged_attention(q[:, 0], kp, vp, tables, lens,
+                            interpret=True).reshape(B, 1, H, D)
     np.testing.assert_allclose(np.asarray(dense), np.asarray(paged),
                                rtol=2e-3, atol=2e-3)

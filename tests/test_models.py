@@ -82,6 +82,24 @@ def test_prefill_decode_consistency(arch):
                                rtol=3e-3, atol=3e-3)
 
 
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_decode_state_axes_match_state(arch):
+    """decode_state_axes mirrors init_decode_state leaf for leaf, and its
+    "cache_batch" axis is the one axis that grows with the batch."""
+    cfg = get_smoke_config(arch)
+    shapes = [jax.eval_shape(lambda: T.init_decode_state(cfg, CTX, b, 32))
+              for b in (3, 5)]
+    axes = jax.tree_util.tree_leaves(T.decode_state_axes(cfg),
+                                     is_leaf=T._is_axes)
+    slots = jax.tree_util.tree_leaves(T.decode_slot_axes(cfg))
+    leaves = [jax.tree_util.tree_leaves(s) for s in shapes]
+    assert len(axes) == len(slots) == len(leaves[0])
+    for a, ax, small, big in zip(axes, slots, *leaves):
+        grown = [i for i in range(small.ndim)
+                 if small.shape[i] != big.shape[i]]
+        assert len(a) == small.ndim and grown == [ax], (a, small.shape)
+
+
 def test_mla_paper_model():
     cfg = reduced(DEEPSEEK_R1_671B)
     params = T.init_params(cfg, KEY, CTX, mode="serve", dtype=jnp.float32)
