@@ -1,0 +1,9 @@
+"""95th percentile (nearest rank) of the pooled gaps between consecutive
+tokens of a request inside the window (host clock), in milliseconds."""
+from chipbench import stats
+
+
+def read(run):
+    v = stats.nearest_rank(stats.itls(run.recs, run.t_open, run.t_close),
+                           0.95)
+    return None if v is None else v * 1e3
