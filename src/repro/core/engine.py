@@ -2,6 +2,14 @@
 preemption + KV-aware admission + online concurrency tuning, with identical
 scheduling logic over a real JAX runner or the virtual-clock simulator.
 
+Clocks: with ``virtual_clock=True`` the engine's ``now`` advances by the
+runner's modeled iteration time and jumps idle gaps. With
+``virtual_clock=False`` it is ``time.perf_counter()``: every event carries
+the moment it was emitted, a request's arrival is the moment of ``submit``,
+``t_admitted`` the moment the scheduler admitted it, and a token's time the
+moment its readback put it on the host. The real path also opens host spans
+on the profiler's clock (``repro.trace.annotate``).
+
 Open-loop replay: ``submit(arrival=t)`` with a future ``t`` holds the request
 in a pending heap, invisible to the scheduler until the engine clock reaches
 ``t`` (the cluster layer's arrival-time gating). ``eject``/``inject`` are the
@@ -60,7 +68,11 @@ class InferenceEngine:
                 classes=ClassPolicy(priority=dict(ecfg.class_priorities),
                                     kv_headroom=ecfg.class_kv_headroom)))
         self.virtual_clock = virtual_clock
-        self.now = 0.0
+        self._now = 0.0
+        self._annotate = None
+        if not virtual_clock:
+            from repro.trace import annotate   # imports JAX: real mode only
+            self._annotate = annotate
         # the event spine (repro.trace): every transition this engine (or
         # its scheduler/allocator) performs is emitted exactly once on this
         # log; metrics are a subscriber, not a parallel bookkeeping path
@@ -126,8 +138,22 @@ class InferenceEngine:
     def next_arrival(self) -> Optional[float]:
         return self._pending[0][0] if self._pending else None
 
+    @property
+    def now(self) -> float:
+        """The engine clock: virtual time, or the host's wall clock."""
+        if self.virtual_clock:
+            return self._now
+        # lint: disable=REP002 (real execution: the wall clock IS now)
+        return time.perf_counter()
+
+    @now.setter
+    def now(self, t: float):
+        if not self.virtual_clock:
+            raise ValueError("a real-mode engine's clock is the wall clock")
+        self._now = t
+
     def advance_to(self, t: float):
-        """Fast-forward an idle clock (no in-flight work ages)."""
+        """Fast-forward an idle virtual clock (no in-flight work ages)."""
         self.now = max(self.now, t)
 
     def _release_arrivals(self):
@@ -164,22 +190,37 @@ class InferenceEngine:
 
     def step(self) -> bool:
         """One engine iteration. Returns False when idle."""
+        if self.virtual_clock:
+            return self._step()
+        with self._annotate.step(self._steps):
+            return self._step()
+
+    def _step(self) -> bool:
         self._release_arrivals()
         if not self.sched.has_work:
             nxt = self.next_arrival()
             if nxt is None:
                 return False
-            # open-loop idle gap: jump to the next arrival
-            self.advance_to(nxt)
+            # open-loop idle gap: a virtual clock jumps to the next
+            # arrival, a real one waits for it
+            if self.virtual_clock:
+                self.advance_to(nxt)
+            else:
+                while (wait := nxt - self.now) > 0:
+                    time.sleep(wait)
             self._release_arrivals()
-        # lint: disable=REP002 (real-execution timing, not simulation)
-        # (virtual-clock runs never read t0: the `if self.virtual_clock`
-        # branch below uses the runner's modeled iteration_time instead)
-        t0 = time.monotonic()
-        plan = self.sched.plan_step()
+        if self.virtual_clock:
+            plan = self.sched.plan_step()
+        else:
+            with self._annotate.span("repro.scheduler.plan_step"):
+                plan = self.sched.plan_step()
         for r in plan.admitted:
             if r.t_admitted is None:
                 r.t_admitted = self.now
+        # a token's time is when it is on the host: in real mode when the
+        # runner's readback returned it, in virtual time at the end of the
+        # modeled iteration
+        t_token: Dict[int, float] = {}
 
         # --- execute prefill chunks (the completing chunk emits a token,
         #     vLLM-style: recompute-resume also re-emits its next token)
@@ -188,6 +229,7 @@ class InferenceEngine:
             completing = req.prompt_pos + chunk >= req.prefill_target
             if completing and not self.virtual_clock:
                 tok = self.runner.prefill(req, chunk)
+                t_token[req.rid] = self.now
             else:
                 tok = 0
             req.prompt_pos += chunk
@@ -209,7 +251,9 @@ class InferenceEngine:
         # --- execute decode batch
         if plan.decode and not self.virtual_clock:
             toks = self.runner.decode(plan.decode)
+            t_decode = self.now
             for r, t in zip(plan.decode, toks):
+                t_token[r.rid] = t_decode
                 r.output.append(t)
                 r.generated += 1
         elif plan.decode:
@@ -221,30 +265,24 @@ class InferenceEngine:
             self.emitter.emit("decode_step",
                               rids=[r.rid for r in plan.decode])
 
-        # --- advance the clock
+        # --- advance a virtual clock by the modeled iteration
         if self.virtual_clock:
-            dt, parts = self.runner.iteration_time(plan.prefill_tokens,
+            self.now += self.runner.iteration_time(plan.prefill_tokens,
                                                    plan.decode)
-            self.now += dt
-            hbm_busy = self.runner.hbm_busy_fraction(parts, dt) \
-                if dt else 0.0
-        else:
-            # lint: disable=REP002 (real-execution path: wall time IS now)
-            # (the virtual-clock branch above never reaches this line)
-            self.now += time.monotonic() - t0
-            hbm_busy = 0.0
+            for r in [*completed_prefill, *plan.decode]:
+                t_token[r.rid] = self.now
 
-        # --- timestamps after the iteration completes
+        # --- timestamps
         for req in completed_prefill:
             if req.t_first_token is None:
-                req.t_first_token = self.now
+                req.t_first_token = t_token[req.rid]
         for r in plan.decode:
-            r.decode_times.append(self.now)
+            r.decode_times.append(t_token[r.rid])
 
         # --- finish
         for req in [*plan.decode, *completed_prefill]:
             if req in self.sched.running and req.done and req.prefill_done:
-                req.t_finished = self.now
+                req.t_finished = t_token[req.rid]
                 self.sched.finish(req)
                 if not self.virtual_clock:
                     self.runner.release(req)
@@ -272,7 +310,6 @@ class InferenceEngine:
                 gen_tokens=self._gen_total,
                 prefill_tokens=self._prefill_total,
                 preemptions=self.sched.n_preemptions,
-                hbm_busy=hbm_busy,
                 kv_pages_used=self.alloc.used_pages,
                 kv_pages_free=self.alloc.free_pages,
                 max_seqs=self.sched.cfg.max_num_seqs)

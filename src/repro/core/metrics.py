@@ -191,7 +191,6 @@ class TimelinePoint:
     gen_tokens: int          # cumulative
     prefill_tokens: int      # cumulative
     preemptions: int         # cumulative
-    hbm_busy: float = 0.0    # modeled fraction (sim mode)
     kv_pages_used: int = 0   # absolute page counts (repro.obs windows
     kv_pages_free: int = 0   # consume the stream without engine access)
     max_seqs: int = 0        # live concurrency cap (moves under autotune)

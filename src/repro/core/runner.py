@@ -11,7 +11,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -30,17 +30,15 @@ class SimRunner:
         self.hw = hw
         self.dtype_bytes = dtype_bytes
 
-    def iteration_time(self, prefill_tokens: int, decode_reqs: List[Request]
-                       ) -> Tuple[float, Dict[str, float]]:
+    def iteration_time(self, prefill_tokens: int,
+                       decode_reqs: List[Request]) -> float:
+        """Modeled seconds of one iteration: a prefill of
+        ``prefill_tokens`` and one decode step of ``decode_reqs``."""
         cfg, plan, hw = self.cfg, self.plan, self.hw
-        parts = {"compute": 0.0, "memory": 0.0, "comm": 0.0}
         t = 0.0
         if prefill_tokens:
-            p = pm.prefill_step_time(cfg, prefill_tokens, plan, hw,
-                                     self.dtype_bytes)
-            t += p["total"]
-            for k in parts:
-                parts[k] += p[k]
+            t += pm.prefill_step_time(cfg, prefill_tokens, plan, hw,
+                                      self.dtype_bytes)["total"]
         if decode_reqs:
             mean_ctx = float(np.mean([r.context_len for r in decode_reqs]))
             d = pm.decode_step_time(cfg, len(decode_reqs), mean_ctx, plan, hw,
@@ -50,9 +48,7 @@ class SimRunner:
             t += d["total"] * bubble \
                 + pm.pp_transport_time(cfg, len(decode_reqs), plan, hw,
                                        self.dtype_bytes)
-            for k in parts:
-                parts[k] += d[k]
-        return t, parts
+        return t
 
     def prefill(self, req: Request, chunk: int) -> int:
         return 0   # dummy token id
@@ -63,9 +59,6 @@ class SimRunner:
     def release(self, req: Request):
         pass
 
-    def hbm_busy_fraction(self, parts: Dict[str, float], t: float) -> float:
-        return min(parts["memory"] / t, 1.0) if t > 0 else 0.0
-
 
 class JaxRunner:
     """Real execution with a slot-based decode cache: each running request
@@ -73,34 +66,49 @@ class JaxRunner:
     writes its cache into the slot; decode steps every slot at once and keeps
     the inactive ones unchanged. The cache dtype is the weights' dtype unless
     ``ctx.kv_cache_dtype`` says otherwise; on a mesh the cache is laid out by
-    ``decode_state_shardings``."""
+    ``decode_state_shardings``. The jitted programs are named
+    ``jit_init_decode_state``, ``jit_prefill``, ``jit_insert`` and
+    ``jit_decode`` in compiled modules and device traces."""
 
     def __init__(self, cfg: ModelConfig, params, ctx, max_slots: int,
                  max_len: int):
         import jax
         import jax.numpy as jnp
         from repro.models import transformer as T
+        from repro.trace.annotate import span
         self.cfg, self.params, self.ctx = cfg, params, ctx
         self.max_slots, self.max_len = max_slots, max_len
         self._jnp = jnp
         self._T = T
+        self._span = span
         dt = params["embed"].dtype
         self._slot_axes = T.decode_slot_axes(cfg)
         state_sh = None if ctx.mesh is None \
             else T.decode_state_shardings(cfg, ctx)
         self._pin = (lambda st: st) if state_sh is None else (
             lambda st: jax.lax.with_sharding_constraint(st, state_sh))
-        self.state = jax.jit(
-            lambda: T.init_decode_state(cfg, ctx, max_slots, max_len, dt),
-            out_shardings=state_sh)()
+
+        # jit names a program after its function, so each is a named def
+        def init_decode_state():
+            return T.init_decode_state(cfg, ctx, max_slots, max_len, dt)
+
+        def prefill(p, tok):
+            return T.prefill(p, tok, cfg, ctx, max_len=max_len,
+                             cache_dtype=dt)
+
+        def insert(state, fresh, slot):
+            return self._insert(state, fresh, slot)
+
+        def decode(params, state, tokens, active):
+            return self._masked_decode(params, state, tokens, active)
+
+        self.state = jax.jit(init_decode_state, out_shardings=state_sh)()
         self._free_slots = list(range(max_slots))[::-1]
         self._slot_of: Dict[int, int] = {}
-        self._prefill_fn = jax.jit(
-            lambda p, tok: T.prefill(p, tok, cfg, ctx, max_len=max_len,
-                                     cache_dtype=dt))
+        self._prefill_fn = jax.jit(prefill)
         # the state is donated: each step updates the cache in place
-        self._insert_fn = jax.jit(self._insert, donate_argnums=(0,))
-        self._decode_fn = jax.jit(self._masked_decode, donate_argnums=(1,))
+        self._insert_fn = jax.jit(insert, donate_argnums=(0,))
+        self._decode_fn = jax.jit(decode, donate_argnums=(1,))
 
     def _insert(self, state, fresh, slot):
         import jax
@@ -145,34 +153,40 @@ class JaxRunner:
 
     def prefill(self, req: Request, chunk: int) -> int:
         """Whole-prompt prefill into the request's slot; returns first token."""
-        toks = req.prompt + req.output[:req.resume_extra]
-        # a slot holds max_len positions, and an out-of-range cache write
-        # would be dropped silently on the device
-        if req.isl + req.max_new_tokens - 1 > self.max_len:
-            raise ValueError(
-                f"request {req.rid}: {req.isl} prompt + {req.max_new_tokens} "
-                f"new tokens exceed the {self.max_len}-position slot")
-        if req.rid not in self._slot_of:
-            self._slot_of[req.rid] = self._free_slots.pop()
-        logits = self.prefill_slot(self._slot_of[req.rid], toks)
-        return int(self._jnp.argmax(logits))
+        with self._span("repro.runner.prefill", rid=req.rid):
+            toks = req.prompt + req.output[:req.resume_extra]
+            # a slot holds max_len positions, and an out-of-range cache
+            # write would be dropped silently on the device
+            if req.isl + req.max_new_tokens - 1 > self.max_len:
+                raise ValueError(
+                    f"request {req.rid}: {req.isl} prompt + "
+                    f"{req.max_new_tokens} new tokens exceed the "
+                    f"{self.max_len}-position slot")
+            if req.rid not in self._slot_of:
+                self._slot_of[req.rid] = self._free_slots.pop()
+            with self._span("repro.runner.prefill.dispatch"):
+                logits = self.prefill_slot(self._slot_of[req.rid], toks)
+                tok = self._jnp.argmax(logits)
+            with self._span("repro.runner.prefill.wait"):
+                return int(tok)
 
     def decode(self, reqs: List[Request]) -> List[int]:
-        slots = [self._slot_of[r.rid] for r in reqs]
-        tokens = np.zeros((self.max_slots,), np.int32)
-        active = np.zeros((self.max_slots,), bool)
-        for r, s in zip(reqs, slots):
-            tokens[s] = r.output[-1] if r.output else (
-                r.prompt[-1] if r.prompt else 0)
-            active[s] = True
-        logits = self.decode_slots(tokens, active)
-        nxt = np.asarray(self._jnp.argmax(logits, axis=-1))
-        return [int(nxt[s]) for s in slots]
+        with self._span("repro.runner.decode", n=len(reqs)):
+            slots = [self._slot_of[r.rid] for r in reqs]
+            tokens = np.zeros((self.max_slots,), np.int32)
+            active = np.zeros((self.max_slots,), bool)
+            for r, s in zip(reqs, slots):
+                tokens[s] = r.output[-1] if r.output else (
+                    r.prompt[-1] if r.prompt else 0)
+                active[s] = True
+            with self._span("repro.runner.decode.dispatch"):
+                logits = self.decode_slots(tokens, active)
+                nxt = self._jnp.argmax(logits, axis=-1)
+            with self._span("repro.runner.decode.wait"):
+                nxt = np.asarray(nxt)
+            return [int(nxt[s]) for s in slots]
 
     def release(self, req: Request):
         slot = self._slot_of.pop(req.rid, None)
         if slot is not None:
             self._free_slots.append(slot)
-
-    def iteration_time(self, prefill_tokens, decode_reqs):
-        return None, {}   # real mode: engine uses wall-clock

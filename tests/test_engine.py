@@ -1,6 +1,10 @@
 """End-to-end engine behaviour: real-execution correctness (engine output ==
 straight-line greedy decode, WITH and WITHOUT forced preemption), sim-mode
-capacity-trap dynamics, autotuner, and DP routing."""
+capacity-trap dynamics, autotuner, and DP routing; the real-mode wall
+clock, host spans and program names."""
+import glob
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -121,6 +125,108 @@ def test_engine_preemption_preserves_outputs(small_model):
         "pool was sized to force preemption"
     for p, n, r in zip(prompts, n_new, reqs):
         assert r.output == _greedy_reference(cfg, params, p, n)
+
+
+def _smoke_engine(cfg, params, max_slots=2):
+    runner = JaxRunner(cfg, params, CTX, max_slots=max_slots, max_len=192)
+    ecfg = EngineConfig(n_pages=64, max_num_seqs=max_slots,
+                        max_num_batched_tokens=512, chunk_size=192)
+    return InferenceEngine(cfg, ecfg, runner, virtual_clock=False)
+
+
+def test_real_mode_clock_is_the_wall_clock(small_model):
+    """A real-mode engine stamps requests and events with
+    time.perf_counter(): a request that waits before the first step has
+    waited, a future arrival is waited for and never jumped to, and every
+    timestamp lies between the clock reads around the calls that made it."""
+    cfg, params = small_model
+    eng = _smoke_engine(cfg, params)
+    eng.events.enable_recording()
+    rng = np.random.default_rng(3)
+    t0 = time.perf_counter()
+    first = eng.submit(rng.integers(0, cfg.vocab, 9).tolist(), 4)
+    t1 = time.perf_counter()
+    time.sleep(0.2)
+    eng.run(max_steps=100)
+    t2 = time.perf_counter()
+    later = eng.submit(rng.integers(0, cfg.vocab, 6).tolist(), 3,
+                       arrival=t2 + 0.2)
+    eng.run(max_steps=100)
+    t3 = time.perf_counter()
+    assert t0 <= first.arrival <= t1
+    assert first.waiting_time() >= 0.2
+    assert first.t_finished <= t2 < later.arrival == t2 + 0.2
+    for r in (first, later):
+        assert r.arrival <= r.t_admitted <= r.t_first_token
+        assert [r.t_first_token, *r.decode_times] == \
+            sorted([r.t_first_token, *r.decode_times])
+        assert r.t_finished == r.decode_times[-1] <= t3
+        assert len(r.output) == r.max_new_tokens
+    times = [e.t for e in eng.events.events]
+    assert times == sorted(times) and t0 <= times[0] and times[-1] <= t3
+    with pytest.raises(ValueError, match="wall clock"):
+        eng.advance_to(t3 + 1.0)
+
+
+def test_real_mode_spans_in_a_profiler_trace(small_model, tmp_path):
+    """Under jax.profiler the engine's and runner's host spans are in the
+    trace, each inside its parent, with their stats as event stats."""
+    from jax.profiler import ProfileData
+    from repro.trace.annotate import SPAN_NAMES
+    cfg, params = small_model
+    eng = _smoke_engine(cfg, params)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, cfg.vocab, n).tolist() for n in (7, 11)]
+    for p in prompts:                     # compile outside the trace
+        eng.submit(p, 3)
+    eng.run(max_steps=100)
+    reqs = [eng.submit(p, 3) for p in prompts]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(max_steps=100)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+              dict(ev.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("repro.")]
+    assert {s[0] for s in spans} == set(SPAN_NAMES)
+    parent = {"repro.scheduler.plan_step": "repro.engine.step",
+              "repro.runner.prefill": "repro.engine.step",
+              "repro.runner.decode": "repro.engine.step",
+              "repro.runner.prefill.dispatch": "repro.runner.prefill",
+              "repro.runner.prefill.wait": "repro.runner.prefill",
+              "repro.runner.decode.dispatch": "repro.runner.decode",
+              "repro.runner.decode.wait": "repro.runner.decode"}
+    for name, s, e, _ in spans:
+        if name in parent:
+            assert any(n == parent[name] and ps <= s and e <= pe
+                       for n, ps, pe, _ in spans), name
+    stats = lambda n: [st for name, _, _, st in spans if name == n]  # noqa
+    assert sorted(st["rid"] for st in stats("repro.runner.prefill")) == \
+        [r.rid for r in reqs]
+    assert all(st["n"] in (1, 2) for st in stats("repro.runner.decode"))
+    assert all("step_num" in st for st in stats("repro.engine.step"))
+
+
+def test_runner_programs_have_stable_names(small_model):
+    """The jitted steps compile to modules named after what they do, which
+    is how a device trace names their operations."""
+    cfg, params = small_model
+    runner = JaxRunner(cfg, params, CTX, max_slots=2, max_len=64)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    _, fresh = runner._prefill_fn(params, tokens)
+    lowered = {
+        "jit_prefill": runner._prefill_fn.lower(params, tokens),
+        "jit_insert": runner._insert_fn.lower(runner.state, fresh, 0),
+        "jit_decode": runner._decode_fn.lower(
+            params, runner.state, jnp.zeros((2, 1), jnp.int32),
+            jnp.zeros((2,), bool))}
+    for name, low in lowered.items():
+        assert f"module @{name} " in low.as_text()
 
 
 def _sim_engine(cfg, max_seqs, n_pages, admission="naive", autotune=False):

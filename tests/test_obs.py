@@ -152,13 +152,16 @@ def test_windows_see_migration_traffic_on_the_destination():
 
 def test_step_payload_feeds_windows_without_engine_access():
     """The PR-9 step-payload extension: absolute KV page counts and the
-    live cap are in the stream, so windows get them post-hoc."""
+    live cap are in the stream, so windows get them post-hoc. The payload
+    holds counts the engine has, and no modeled fraction."""
     events = _cluster_run(_shrunk(COLOCATED, 8)).events.events
     steps = [e for e in events if e.kind == "step"]
     assert steps
     for e in steps:
-        assert {"kv_pages_used", "kv_pages_free", "max_seqs"} <= \
-            set(e.payload)
+        assert set(e.payload) == {
+            "running", "waiting", "kv_util", "kv_frag", "gen_tokens",
+            "prefill_tokens", "preemptions", "kv_pages_used",
+            "kv_pages_free", "max_seqs"}
     ws = build_windows(events)
     assert any(w.kv_pages_used_max > 0 for w in ws.all_windows())
     assert all(w.max_seqs > 0 for w in ws.all_windows() if w.n_samples)
