@@ -64,9 +64,11 @@ class JaxRunner:
     """Real execution with a slot-based decode cache: each running request
     owns one slot of ``max_len`` positions. Prefill runs the whole prompt and
     writes its cache into the slot; decode steps every slot at once and keeps
-    the inactive ones unchanged. The cache dtype is the weights' dtype unless
-    ``ctx.kv_cache_dtype`` says otherwise; on a mesh the cache is laid out by
-    ``decode_state_shardings``. The jitted programs are named
+    the inactive ones unchanged: their caches because the step merges a new
+    token only into active slots (``decode_step``), their lengths and
+    recurrent states by a per-slot select. The cache dtype is the weights'
+    dtype unless ``ctx.kv_cache_dtype`` says otherwise; on a mesh the cache
+    is laid out by ``decode_state_shardings``. The jitted programs are named
     ``jit_init_decode_state``, ``jit_prefill``, ``jit_insert`` and
     ``jit_decode`` in compiled modules and device traces."""
 
@@ -119,15 +121,21 @@ class JaxRunner:
 
     def _masked_decode(self, params, state, tokens, active):
         import jax
-        logits, new_state = self._T.decode_step(params, state, tokens,
-                                                self.cfg, self.ctx)
+        T = self._T
+        logits, new_state = T.decode_step(params, state, tokens, self.cfg,
+                                          self.ctx, active)
 
-        def keep_inactive(new, old, ax):
+        # decode_step leaves an inactive slot's positional caches (leaves
+        # with a "cache_seq" axis) as they were; the other leaves (lens, the
+        # recurrent states) are per-slot and small, and are selected here
+        def keep_inactive(new, old, axes):
+            if "cache_seq" in axes:
+                return new
             shape = [1] * new.ndim
-            shape[ax] = self.max_slots
+            shape[axes.index("cache_batch")] = self.max_slots
             return self._jnp.where(active.reshape(shape), new, old)
         merged = jax.tree_util.tree_map(keep_inactive, new_state, state,
-                                        self._slot_axes)
+                                        T.decode_state_axes(self.cfg))
         return logits[:, 0], self._pin(merged)
 
     # ------------------------------------------------------------------ api

@@ -101,9 +101,14 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      lens: jax.Array, *, window: int = 0,
-                     scale: Optional[float] = None) -> jax.Array:
+                     scale: Optional[float] = None,
+                     k_new: Optional[jax.Array] = None,
+                     v_new: Optional[jax.Array] = None) -> jax.Array:
     """q (B,1,H,D); caches (B,S,KV,D); lens (B,) = index of the newest token
-    (attention covers positions 0..lens inclusive). Returns (B,1,H,D)."""
+    (attention covers positions 0..lens inclusive). With ``k_new`` and
+    ``v_new`` (B,1,KV,D) the newest token is not in the cache: the cache is
+    read below ``lens`` and the token is attended beside it, so the caller
+    may write it afterwards. Returns (B,1,H,D)."""
     B, _, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
     g = H // KV
@@ -112,15 +117,29 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
                    preferred_element_type=jnp.float32)
     pos = jnp.arange(S, dtype=jnp.int32)
-    valid = pos[None, :] <= lens.astype(jnp.int32)[:, None]
+    lens = lens.astype(jnp.int32)
+    newest = lens if k_new is None else lens - 1
+    valid = pos[None, :] <= newest[:, None]
     if window and window > 0:
-        valid &= pos[None, :] > lens.astype(jnp.int32)[:, None] - window
+        valid &= pos[None, :] > lens[:, None] - window
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache,
+    m = jnp.max(s, axis=-1)
+    if k_new is not None:
+        s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new[:, 0],
+                           preferred_element_type=jnp.float32)
+        m = jnp.maximum(m, s_new)
+    p = jnp.exp(s - m[..., None])
+    den = jnp.sum(p, axis=-1)
+    if k_new is not None:
+        p_new = jnp.exp(s_new - m)
+        den = den + p_new
+    out = jnp.einsum("bkgs,bskd->bkgd",
+                     (p / den[..., None]).astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
+    if v_new is not None:
+        out = out + jnp.einsum("bkg,bkd->bkgd",
+                               (p_new / den).astype(v_new.dtype), v_new[:, 0],
+                               preferred_element_type=jnp.float32)
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
@@ -156,11 +175,14 @@ def mla_prefill(x, p, cfg, positions, kv_lens=None):
     return out, (ckv, k_pe)
 
 
-def mla_decode(x, p, cfg, ckv_cache, kpe_cache, lens):
-    """Absorbed MLA decode: the cache is the latent (B,S,rank)+(B,S,rope)."""
+def mla_decode(x, p, cfg, ckv_cache, kpe_cache, lens, ckv_new, kpe_new):
+    """Absorbed MLA decode: the cache is the latent (B,S,rank)+(B,S,rope),
+    read below ``lens``; the newest token's latents ``ckv_new`` (B,1,rank)
+    and ``kpe_new`` (B,1,rope) are attended beside it, as
+    ``decode_attention`` does with ``k_new``."""
     from repro.models.common import rmsnorm, rope
     ml = cfg.mla
-    B = x.shape[0]
+    S = ckv_cache.shape[1]
     pos = lens.astype(jnp.int32)
     cq = rmsnorm(x @ p["w_dq"], p["q_norm"], cfg.norm_eps)
     qs = jnp.einsum("bsr,rhe->bshe", cq, p["w_uq"])
@@ -168,14 +190,18 @@ def mla_decode(x, p, cfg, ckv_cache, kpe_cache, lens):
     q_pe = rope(qs[..., ml.qk_nope_head_dim:], pos[:, None], cfg.rope_theta)
     q_lat = jnp.einsum("bshe,rhe->bshr", q_nope, p["w_uk"])          # absorb w_uk
     scale = (ml.qk_nope_head_dim + ml.qk_rope_head_dim) ** -0.5
-    s = (jnp.einsum("bshr,btr->bhst", q_lat, ckv_cache)
-         + jnp.einsum("bshe,bte->bhst", q_pe, kpe_cache)) * scale
-    s = s.astype(jnp.float32)[:, :, 0, :]                            # (B,H,S)
-    t = jnp.arange(ckv_cache.shape[1], dtype=jnp.int32)
-    valid = t[None, :] <= pos[:, None]
-    s = jnp.where(valid[:, None, :], s, NEG_INF)
+
+    def scores(ckv, kpe):                                            # (B,H,T)
+        return ((jnp.einsum("bshr,btr->bhst", q_lat, ckv)
+                 + jnp.einsum("bshe,bte->bhst", q_pe, kpe)) * scale
+                ).astype(jnp.float32)[:, :, 0, :]
+    t = jnp.arange(S, dtype=jnp.int32)
+    s = jnp.where((t[None, :] < pos[:, None])[:, None, :],
+                  scores(ckv_cache, kpe_cache), NEG_INF)
+    s = jnp.concatenate([s, scores(ckv_new, kpe_new)], axis=-1)
     w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    ctx_lat = jnp.einsum("bht,btr->bhr", w, ckv_cache)
+    ctx_lat = (jnp.einsum("bht,btr->bhr", w[..., :S], ckv_cache)
+               + jnp.einsum("bht,btr->bhr", w[..., S:], ckv_new))
     ctx = jnp.einsum("bhr,rhe->bhe", ctx_lat, p["w_uv"])             # absorb w_uv
     out = jnp.einsum("bhe,hed->bd", ctx, p["w_o"])
     return out[:, None, :]

@@ -447,63 +447,75 @@ def _attn_mlp_layer_fwd(x, p, cfg, ctx, positions, mode, *, window,
     return (x, (k, v)) if return_kv else (x, None)
 
 
-def _attn_mlp_layer_decode(x, p, cfg, ctx, cache, lens, *, window):
-    """cache: dict(k (B,S,kvx,hd), v ...) or MLA latents. Returns x, new cache."""
+_WRITE_BLOCK = 128      # positions per cache write: one lane tile on a TPU
+
+
+def _attn_mlp_layer_decode(x, p, cfg, ctx, cache, l, lens, *, window):
+    """Layer ``l`` of a stack whose cache (dict of stacked (L,B,S,...) k/v or
+    MLA latents) is read in place: attention takes the cached positions
+    below ``lens`` and the new token beside them. Returns x and the new
+    token's cache entries (B,1,...) in the cache's dtype, which
+    ``_write_tokens`` stores after the layer loop."""
     _, _, layout = _gqa_layout(cfg, ctx, "serve")
     if cfg.attention == "mla":
         h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-        ml = cfg.mla
         ckv = rmsnorm(h @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)
         kpe = rope((h @ p["w_kr"])[:, :, None, :], lens[:, None], cfg.rope_theta)[:, :, 0]
-        ckv_c = _insert_seq(cache["ckv"], ckv, lens)
-        kpe_c = _insert_seq(cache["kpe"], kpe, lens)
-        y = attn.mla_decode(h, p, cfg, ckv_c, kpe_c, lens)
+        new = {"ckv": ckv.astype(cache["ckv"].dtype),
+               "kpe": kpe.astype(cache["kpe"].dtype)}
+        y = attn.mla_decode(h, p, cfg, cache["ckv"][l], cache["kpe"][l], lens,
+                            new["ckv"], new["kpe"])
         x = x + y
         x = x + _mlp(x, p, cfg, ctx)
-        return x, {"ckv": ckv_c, "kpe": kpe_c}
+        return x, new
     q, k, v = _attn_qkv(x, p, cfg, lens[:, None], ctx)
-    kc = _insert_kv(cache["k"], k, lens)
-    vc = _insert_kv(cache["v"], v, lens)
-    o = _decode_gqa(q, kc, vc, lens, layout, window=window)
+    new = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+    o = _decode_gqa(q, cache["k"][l], cache["v"][l], lens, layout,
+                    window=window, k_new=new["k"], v_new=new["v"])
     x = x + jnp.einsum("bshe,hed->bsd", o, p["wo"])
     x = x + _mlp(x, p, cfg, ctx)
-    return x, {"k": kc, "v": vc}
+    return x, new
 
 
-def _insert_kv(cache, new, lens):
-    """cache (B,S,kv,hd); new (B,1,kv,hd); lens (B,)."""
-    def one(c, n, l):
-        return jax.lax.dynamic_update_slice(c, n, (l, 0, 0))
-    return jax.vmap(one)(cache, new.astype(cache.dtype), lens.astype(jnp.int32))
+def _write_tokens(cache, new, lens, active):
+    """Write each active slot's new token of every layer, ``new`` (L,B,1,...),
+    into the stacked cache (L,B,S,...) at its position ``lens``, in place.
+
+    Each slot reads the aligned block of ``_WRITE_BLOCK`` positions that
+    holds ``lens`` and writes it back with its tokens merged in where the
+    slot is active, so an inactive slot's bytes come back unchanged (a slot
+    at ``lens == S`` merges nothing). One dynamic update per slot and step
+    leaves the cache in the layout the attention reads: on a v5e with
+    120-wide heads the sequence is the minor axis, and a scatter, whose
+    window must be minor, makes the compiler copy the whole cache into
+    another layout and back."""
+    (L, B, S), rest = cache.shape[:3], cache.shape[3:]
+    w = min(_WRITE_BLOCK, S)
+    start = jnp.minimum(jax.lax.div(lens, w) * w, S - w)
+    hit = (jnp.arange(w) == (lens - start)[:, None]) & active[:, None]
+    hit = hit.reshape((B, 1, 1, w) + (1,) * len(rest))
+    tail = (0,) * len(rest)
+    for b in range(B):
+        at = (0, b, start[b]) + tail
+        block = jax.lax.dynamic_slice(cache, at, (L, 1, w) + rest)
+        block = jnp.where(hit[b], new[:, b:b + 1], block)
+        cache = jax.lax.dynamic_update_slice(cache, block, at)
+    return cache
 
 
 def _decode_unrolled_stack(x, stack_params, cache, cfg, ctx, lens, window):
     """Unrolled decode over a homogeneous stack with stacked caches
-    (L,B,S,kv,hd): per-layer params/cache use *static* indices, the new
-    token is scattered in place, and attention dots read the cache slice
-    directly (no materialised per-layer copies)."""
-    kc, vc = cache["k"], cache["v"]
-    L = kc.shape[0]
-    B = x.shape[0]
-    _, _, layout = _gqa_layout(cfg, ctx, "serve")
-    bidx = jnp.arange(B, dtype=jnp.int32)
+    (L,B,S,kv,hd): per-layer params/cache use *static* indices, and
+    attention dots read the cache slice directly (no materialised per-layer
+    copies). Returns x and the new tokens, stacked as ``lax.scan`` would."""
+    L = cache["k"].shape[0]
+    news = []
     for l in range(L):
         p = jax.tree_util.tree_map(lambda a: a[l], stack_params)
-        q, k, v = _attn_qkv(x, p, cfg, lens[:, None], ctx)
-        kc = kc.at[l, bidx, lens].set(k[:, 0].astype(kc.dtype))
-        vc = vc.at[l, bidx, lens].set(v[:, 0].astype(vc.dtype))
-        o = _decode_gqa(q, kc[l].astype(q.dtype), vc[l].astype(q.dtype),
-                        lens, layout, window=window)
-        x = x + jnp.einsum("bshe,hed->bsd", o, p["wo"])
-        x = x + _mlp(x, p, cfg, ctx)
-    return x, {"k": kc, "v": vc}
-
-
-def _insert_seq(cache, new, lens):
-    """cache (B,S,r); new (B,1,r)."""
-    def one(c, n, l):
-        return jax.lax.dynamic_update_slice(c, n, (l, 0))
-    return jax.vmap(one)(cache, new.astype(cache.dtype), lens.astype(jnp.int32))
+        x, new = _attn_mlp_layer_decode(x, p, cfg, ctx, cache, l, lens,
+                                        window=window)
+        news.append(new)
+    return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *news)
 
 
 def _maybe_remat(fn, ctx):
@@ -703,50 +715,60 @@ def init_decode_state(cfg: ModelConfig, ctx: ParallelContext, batch: int,
     return state
 
 
-def decode_step(params, state, tokens, cfg: ModelConfig, ctx: ParallelContext):
-    """One decode step for the whole batch. tokens (B,1) -> logits (B,1,V)."""
+def decode_step(params, state, tokens, cfg: ModelConfig, ctx: ParallelContext,
+                active=None):
+    """One decode step for the whole batch. tokens (B,1) -> logits (B,1,V).
+
+    ``active`` (B,) bool marks the slots to advance, every slot when None.
+    An active slot's new keys and values are written in place at its
+    ``lens``, which then advances; an inactive slot's cache comes back bit
+    for bit and its length stays, because no token is merged into it
+    (``_write_tokens``). The recurrent states (mamba, xLSTM) are recomputed
+    for every slot: a caller that pauses slots keeps their old values
+    itself (``JaxRunner``)."""
     x = jnp.take(params["embed"], tokens, axis=0)
     lens = state["lens"]
+    if active is None:
+        active = jnp.ones(lens.shape, bool)
     window = cfg.swa_window if cfg.attention == "swa" else 0
     new_state = dict(state)
 
     if cfg.family in ("dense", "vlm", "audio", "moe"):
         caches = state["caches"]
         new_caches = {}
-        for name in ("dense_stack", "moe_stack"):
-            if name not in params:
-                continue
+        for name, n in _cache_stacks(cfg):
             if ctx.decode_unroll and cfg.attention != "mla":
-                # §Perf: unrolled layers + static cache indexing — the scan's
-                # per-layer cache slice/update round-trips become an in-place
-                # one-token scatter (dots read the stacked cache directly)
-                x, nc = _decode_unrolled_stack(x, params[name], caches[name],
-                                               cfg, ctx, lens, window)
+                # §Perf: unrolled layers + static cache indexing
+                x, new = _decode_unrolled_stack(x, params[name], caches[name],
+                                                cfg, ctx, lens, window)
             else:
-                def body(x, pc):
-                    p, c = pc
-                    x, nc = _attn_mlp_layer_decode(x, p, cfg, ctx, c, lens,
-                                                   window=window)
-                    return x, nc
-                x, nc = jax.lax.scan(body, x, (params[name], caches[name]))
-            new_caches[name] = nc
+                # the cache is neither the loop's xs nor its ys: each layer
+                # reads its own slice of it, and its new token comes out
+                def body(x, pl):
+                    p, l = pl
+                    return _attn_mlp_layer_decode(x, p, cfg, ctx, caches[name],
+                                                  l, lens, window=window)
+                x, new = jax.lax.scan(
+                    body, x, (params[name], jnp.arange(n, dtype=jnp.int32)))
+            new_caches[name] = {k: _write_tokens(c, new[k], lens, active)
+                                for k, c in caches[name].items()}
         new_state["caches"] = new_caches
     elif cfg.family == "hybrid":
-        x, new_state = _hybrid_decode(x, params, state, cfg, ctx, lens)
+        x, new_state = _hybrid_decode(x, params, state, cfg, ctx, lens,
+                                      active)
     elif cfg.family == "ssm":
         x, new_state = _xlstm_decode(x, params, state, cfg, ctx)
-        new_state["lens"] = lens
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    new_state["lens"] = lens + 1
+    new_state["lens"] = lens + active.astype(lens.dtype)
     return logits, new_state
 
 
-def _hybrid_decode(x, params, state, cfg, ctx, lens):
+def _hybrid_decode(x, params, state, cfg, ctx, lens, active):
     groups = cfg.n_layers // cfg.attn_every
     per = cfg.attn_every
     shared = params["shared_attn"]
@@ -754,23 +776,25 @@ def _hybrid_decode(x, params, state, cfg, ctx, lens):
         lambda a: a.reshape(groups, per, *a.shape[1:]), params["mamba_stack"])
     mstate = jax.tree_util.tree_map(
         lambda a: a.reshape(groups, per, *a.shape[1:]), state["mamba"])
+    cache = state["caches"]["shared_attn"]
 
     def group_body(x, inp):
-        pg, cache_g, mst_g = inp
-        x, nc = _attn_mlp_layer_decode(x, shared, cfg, ctx, cache_g, lens,
-                                       window=0)
+        pg, mst_g, g = inp
+        x, new = _attn_mlp_layer_decode(x, shared, cfg, ctx, cache, g, lens,
+                                        window=0)
 
         def m_body(x, pm_st):
             pm, st = pm_st
             y, nst = ssm_lib.mamba2_decode(x, pm, cfg, st)
             return x + y, nst
         x, nms = jax.lax.scan(m_body, x, (pg, mst_g))
-        return x, (nc, nms)
+        return x, (new, nms)
 
-    x, (ncaches, nmamba) = jax.lax.scan(
-        group_body, x, (mstack, state["caches"]["shared_attn"], mstate))
+    x, (new, nmamba) = jax.lax.scan(
+        group_body, x, (mstack, mstate, jnp.arange(groups, dtype=jnp.int32)))
     new_state = dict(state)
-    new_state["caches"] = {"shared_attn": ncaches}
+    new_state["caches"] = {"shared_attn": {
+        k: _write_tokens(c, new[k], lens, active) for k, c in cache.items()}}
     new_state["mamba"] = jax.tree_util.tree_map(
         lambda a: a.reshape(cfg.n_layers, *a.shape[2:]), nmamba)
     return x, new_state

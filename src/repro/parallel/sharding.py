@@ -39,7 +39,7 @@ class ParallelContext:
     moe_dispatch: str = "auto"                # auto | split | replicated
     rules_override: Optional[Dict[str, Any]] = None
     # ---- §Perf hillclimb levers (EXPERIMENTS.md §Perf) ----------------------
-    decode_unroll: bool = False     # unrolled decode layers + in-place scatter
+    decode_unroll: bool = False     # unrolled decode layers, static cache indices
     serve_2d_tp: bool = False       # contract-dim TP over "data" (no FSDP
                                     # weight gathers in decode; Pope et al.)
     seq_parallel_norm: bool = False  # Megatron-SP residual stream (prefill)
